@@ -20,10 +20,6 @@ subset speedup >= the 1.3x floor.
 """
 from __future__ import annotations
 
-import os
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 from repro.compiler import compile_query_detailed, interpreter, tensorize
 from repro.compiler.tpch_ir import QUERY_IDS
 from repro.core import engine

@@ -25,8 +25,8 @@ Map        derive lambdas written against numpy trace through a
 Aggregate  keyed: mixed-radix key codes over the *observed* per-key value
            bounds (see below) -> ``jax.ops.segment_sum``-family
            reductions, group compaction by cumsum+searchsorted — no sort
-           anywhere; falls back to a lexsorted-key-encoding path when a
-           key is non-integral or the code domain is too large.
+           anywhere; when the code domain is too large, integral keys
+           sort as one packed code and non-integral keys lexsort.
            keyless: masked whole-column reductions
 Join       build-host / probe-device: every right side is materialized
            host-side as a named build leaf, and a dense key LUT over its
@@ -72,9 +72,10 @@ input is padded to a power-of-two row bucket, so repeated runs at
 similar cardinalities reuse the compiled program (the jit cache is keyed
 by ``(stage, generation, inputs, dtypes, buckets)`` — hit/miss
 accounting is returned per run and surfaced in ``QueryRun``). All tensor
-arithmetic runs under ``jax.experimental.enable_x64`` so results stay
-comparable with the float64 numpy oracle; the interpreter remains that
-oracle and ``tests/test_tensorize.py`` pins identity across all 15 TPC-H
+arithmetic runs under ``jax.enable_x64(True)`` so results stay
+comparable with the float64 numpy oracle (on a TPU, XLA emulates f64 and
+i64, so agreement is to ``engine.results_equal``'s tolerance, not
+bitwise); the interpreter remains that oracle and ``tests/test_tensorize.py`` pins identity across all 15 TPC-H
 residuals, every execution mode, and random decision vectors.
 
 ``core.runtime.run_residual`` dispatches between the two backends
@@ -100,8 +101,9 @@ from repro.queryproc import expressions_jax as exj
 from repro.queryproc.table import ColumnTable
 
 _MIN_BUCKET = 16
-_LUT_CAP = 1 << 23       # max dense key-LUT domain (32 MiB of int32-ish)
+_LUT_CAP = 1 << 25       # max dense key-LUT domain (256 MiB of int64)
 _AGG_DOM_CAP = 1 << 18   # max mixed-radix aggregate code domain
+_LEX_CODE_CAP = 1 << 62  # max key domain sorted as one packed int64 code
 _RESPEC_CAP = 8          # re-specializations before settling on the oracle
 
 
@@ -115,11 +117,6 @@ class TensorFallback(Exception):
     def __init__(self, msg: str = "", respec: bool = False):
         super().__init__(msg)
         self.respec = respec
-
-
-def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
 
 
 class _MT:
@@ -262,7 +259,7 @@ class _Artifact:
     gen: int = 0
     respecs: int = 0
     lock: threading.Lock = dataclasses.field(default_factory=threading.Lock)
-    disabled: bool = False           # tracing failed / respec cap: oracle-only
+    disabled: bool = False           # respec cap reached: oracle-only
 
 
 @dataclasses.dataclass
@@ -274,6 +271,7 @@ class TensorRun:
     fell_back: bool = False
     observed: bool = False
     n_stages: int = 0
+    platforms: Tuple[str, ...] = ()   # devices the jitted stages ran on
 
 
 # ------------------------------------------------------------ compilation
@@ -393,7 +391,8 @@ def compile_residual(residual: ir.Node) -> _Artifact:
         index=len(stages), roots=roots,
         jit_roots=tuple(r for r in roots if not _host_res(r, hmemo)),
         pyop=None, out_name=None))
-    with _x64():
+    import jax
+    with jax.enable_x64(True):
         preds = {id(n): exj.compile_expr_jnp(n.predicate)
                  for n in ir.walk(residual) if isinstance(n, ir.Filter)}
     agg_nodes = [n for n in ir.walk(residual)
@@ -416,7 +415,7 @@ def _observe(art: _Artifact, memo: Dict[int, ColumnTable]) -> None:
     join: Dict[int, Tuple] = {}
     for node in art.agg_nodes:
         spec = agg.get(id(node))
-        if spec is not None and spec[0] == "lex":
+        if spec == ("lex",):
             continue  # non-integral keys are sticky: stay on the sort path
         ct = memo.get(id(node.child))
         if ct is None:
@@ -443,8 +442,12 @@ def _observe(art: _Artifact, memo: Dict[int, ColumnTable]) -> None:
         dom = 1
         for d in dims:
             dom *= d
-        agg[id(node)] = (("code", tuple(mins), tuple(dims))
-                         if dom <= _AGG_DOM_CAP else ("lex",))
+        if dom <= _AGG_DOM_CAP:
+            agg[id(node)] = ("code", tuple(mins), tuple(dims))
+        elif dom < _LEX_CODE_CAP:
+            agg[id(node)] = ("lex", tuple(mins), tuple(dims))
+        else:
+            agg[id(node)] = ("lex",)
     for j, node in enumerate(art.jn_nodes):
         mode: Tuple = ("sorted",)
         rname = art.leaf_names.get(id(node.right))
@@ -502,6 +505,8 @@ def _stage_io(art: _Artifact, st: _Stage
 
 def _build_jits(art: _Artifact) -> None:
     import jax
+    from repro import jaxcache
+    jaxcache.enable_compile_cache()
     fns: List[Optional[Callable]] = []
     for st in art.stages:
         st.names, st.luts = _stage_io(art, st)
@@ -600,7 +605,7 @@ def _lower_aggregate(node: ir.Aggregate, t: _MT, ctx: Dict) -> _MT:
     spec = ctx["art"].obs["agg"][id(node)]
     if spec[0] == "code":
         return _agg_code(node, t, spec, ctx)
-    return _agg_lex(node, t)
+    return _agg_lex(node, t, spec, ctx)
 
 
 def _agg_keyless(node: ir.Aggregate, t: _MT) -> _MT:
@@ -628,6 +633,32 @@ def _agg_keyless(node: ir.Aggregate, t: _MT) -> _MT:
     return _MT(out, jnp.ones((1,), bool))
 
 
+def _radix_code(node: ir.Aggregate, t: _MT, mins, dims, ctx: Dict):
+    """Each row's group keys as one mixed-radix int64 code over the
+    observed per-key bounds (ascending code order == ascending
+    lexicographic key order). Rows whose keys left the bounds raise the
+    in-trace respec flag. Returns (domain size, strides, codes)."""
+    import jax.numpy as jnp
+
+    D = 1
+    for d in dims:
+        D *= d
+    strides = []
+    s = 1
+    for d in reversed(dims):
+        strides.append(s)
+        s *= d
+    strides = list(reversed(strides))
+    oob = jnp.zeros(t.valid.shape, bool)
+    code = jnp.zeros(t.valid.shape, jnp.int64)
+    for k, mn, d, stp in zip(node.keys, mins, dims, strides):
+        off = t.cols[k].astype(jnp.int64) - mn
+        oob = oob | (off < 0) | (off >= d)
+        code = code + jnp.clip(off, 0, d - 1) * stp
+    ctx["respec"].append(jnp.any(t.valid & oob))
+    return D, strides, code
+
+
 def _agg_code(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
     """Sort-free grouped aggregation: each row's keys encode into one
     mixed-radix code over the observed per-key bounds, segment reductions
@@ -640,26 +671,8 @@ def _agg_code(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
     import jax.numpy as jnp
 
     _, mins, dims = spec
-    D = 1
-    for d in dims:
-        D *= d
-    strides = []
-    s = 1
-    for d in reversed(dims):
-        strides.append(s)
-        s *= d
-    strides = list(reversed(strides))
-
-    oob = jnp.zeros(t.valid.shape, bool)
-    code = jnp.zeros(t.valid.shape, jnp.int64)
-    key_dtypes = []
-    for k, mn, d, stp in zip(node.keys, mins, dims, strides):
-        col = t.cols[k]
-        key_dtypes.append(col.dtype)
-        off = col.astype(jnp.int64) - mn
-        oob = oob | (off < 0) | (off >= d)
-        code = code + jnp.clip(off, 0, d - 1) * stp
-    ctx["respec"].append(jnp.any(t.valid & oob))
+    D, strides, code = _radix_code(node, t, mins, dims, ctx)
+    key_dtypes = [t.cols[k].dtype for k in node.keys]
 
     # Small domains lower to a one-hot contraction (XLA:CPU dots are
     # multi-threaded; its segment scatters are not). Large domains keep
@@ -708,26 +721,37 @@ def _agg_code(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
     return _MT(out, jnp.arange(D) < n_groups)
 
 
-def _agg_lex(node: ir.Aggregate, t: _MT) -> _MT:
-    """General grouped aggregation for non-integral or huge-domain keys:
-    lexsorted key encoding -> group-boundary flags -> segment reductions.
-    Slower than ``_agg_code`` (XLA:CPU sorts are single-threaded) but
-    makes no assumption about the key values."""
+def _agg_lex(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
+    """Grouped aggregation for huge-domain or non-integral keys: sorted
+    keys -> group-boundary flags -> segment reductions. Integral keys
+    (``spec`` carries their observed bounds) sort as one mixed-radix
+    code, so the sort has one key whatever the number of group keys:
+    XLA:TPU's compile time for a sort grows steeply with its key count.
+    Other keys lexsort. Slower than ``_agg_code`` (sorts), but only the
+    integral case assumes anything about the key values."""
     import jax
     import jax.numpy as jnp
 
     n = t.valid.shape[0]
     key_arrs = [t.cols[k] for k in node.keys]
-    # primary sort key pushes invalid rows last; groups are contiguous
-    # runs of equal keys among the valid prefix (lexicographic ascending
-    # — the exact group order np.unique gives the interpreter)
-    inval = (~t.valid).astype(jnp.int32)
-    order = jnp.lexsort(tuple(reversed(key_arrs)) + (inval,))
+    # invalid rows sort last; groups are contiguous runs of equal keys
+    # among the valid prefix (lexicographic ascending — the exact group
+    # order np.unique gives the interpreter)
+    if len(spec) == 3:
+        D, _, code = _radix_code(node, t, spec[1], spec[2], ctx)
+        code = jnp.where(t.valid, code, D)
+        code, order = jax.lax.sort((code, jax.lax.iota(jnp.int32, n)),
+                                   num_keys=1, is_stable=True)
+        runs = [code]
+    else:
+        inval = (~t.valid).astype(jnp.int32)
+        order = jnp.lexsort(tuple(reversed(key_arrs)) + (inval,))
+        runs = [a[order] for a in key_arrs]
     vs = t.valid[order]
     ks = [a[order] for a in key_arrs]
     if n > 1:
         same = jnp.ones((n - 1,), bool)
-        for a in ks:
+        for a in runs:
             same = same & (a[1:] == a[:-1])
         changed = jnp.concatenate([jnp.ones((1,), bool), ~same])
     else:
@@ -895,16 +919,23 @@ def _build_lut(rt: ColumnTable, rkey: str, is_join: bool
 # ------------------------------------------------------- artifact caching
 _ART_CACHE: "OrderedDict[int, Tuple[ir.Node, _Artifact]]" = OrderedDict()
 _ART_CACHE_CAP = 128
+# run_stream's compute pool calls execute from several threads
+_ART_LOCK = threading.Lock()
 
 
 def _artifact(residual: ir.Node) -> _Artifact:
     """Compile-once LRU keyed by residual identity (the node is retained,
     so its id cannot be reused while cached) — same discipline as
     ``executor.compile_push_plan`` and the interpreter's ``_PRED_CACHE``."""
-    hit = _ART_CACHE.get(id(residual))
-    if hit is not None and hit[0] is residual:
-        _ART_CACHE.move_to_end(id(residual))
-        return hit[1]
+    with _ART_LOCK:
+        hit = _ART_CACHE.get(id(residual))
+        if hit is not None and hit[0] is residual:
+            _ART_CACHE.move_to_end(id(residual))
+            return hit[1]
+        return _compile_artifact(residual)
+
+
+def _compile_artifact(residual: ir.Node) -> _Artifact:
     tr = obs_trace.get_tracer()
     with tr.span("residual_compile", cat="compiler",
                  shape=ir.describe(residual)) as sp:
@@ -997,8 +1028,12 @@ def _respecialize(art: _Artifact, residual: ir.Node,
 
 def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
     """Run a residual through the tensor backend. Results are identical to
-    ``interpreter.run`` (the oracle); on a lowering-guard trip the oracle
-    is replayed host-side and ``fell_back`` is set."""
+    ``interpreter.run`` (the oracle); on a lowering-guard trip
+    (``TensorFallback``) the oracle is replayed host-side and ``fell_back``
+    is set. Any other error — a lowering, compile or device failure —
+    counts in ``residual.errors`` and raises: it never turns into a
+    silent interpreter run."""
+    import jax
     from repro.compiler import interpreter
 
     art = _artifact(residual)
@@ -1019,6 +1054,7 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
     host_tabs: Dict[str, ColumnTable] = {}
     result: Optional[ColumnTable] = None
     fell_back = False
+    platforms: set = set()
 
     def host_tab(name: str) -> ColumnTable:
         t = env.get(name)
@@ -1031,7 +1067,7 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
         return t
 
     try:
-        with _x64():
+        with jax.enable_x64(True):
             for st in art.stages:
                 out_tabs: Dict[int, ColumnTable] = {}
                 if st.jit_roots:
@@ -1059,6 +1095,9 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
                             respec=True)
                     if bool(out["fallback"]):
                         raise TensorFallback(f"stage {st.index}")
+                    platforms.update(d.platform for a in
+                                     jax.tree_util.tree_leaves(out)
+                                     for d in a.devices())
                     if tr.enabled:
                         tr.event("residual_jit_cache", cat="compiler",
                                  stage=st.index, hit=stage_hit,
@@ -1085,19 +1124,15 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
         if result is None:
             result = interpreter.run(residual, merged)
     except Exception:
-        # lowering/tracing failed (e.g. a derive the shim cannot route):
-        # the oracle still answers, and this residual stays on it
-        art.disabled = True
-        fell_back = True
-        m.counter("residual.fallbacks").inc()
         m.counter("residual.errors").inc()
-        result = interpreter.run(residual, merged)
+        raise
     m.counter("residual.tensor.runs").inc()
     m.counter("residual.jit_cache.hits").inc(hits)
     m.counter("residual.jit_cache.misses").inc(misses)
     assert result is not None
     return TensorRun(table=result, jit_hits=hits, jit_misses=misses,
-                     fell_back=fell_back, n_stages=len(art.stages))
+                     fell_back=fell_back, n_stages=len(art.stages),
+                     platforms=tuple(sorted(platforms)))
 
 
 def run(residual: ir.Node, merged: Dict[str, ColumnTable]) -> ColumnTable:
@@ -1171,8 +1206,5 @@ def auto_threshold() -> float:
     elif os.environ.get("REPRO_NO_CALIBRATE"):
         _AUTO_THRESHOLD = float(DEFAULT_RESIDUAL_THRESHOLD)
     else:
-        try:
-            _AUTO_THRESHOLD = calibrate_residual_threshold()
-        except Exception:  # pragma: no cover - calibration is best-effort
-            _AUTO_THRESHOLD = float(DEFAULT_RESIDUAL_THRESHOLD)
+        _AUTO_THRESHOLD = calibrate_residual_threshold()
     return _AUTO_THRESHOLD

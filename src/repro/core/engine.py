@@ -327,12 +327,6 @@ def _run_decided(query: Query, reqs: List[PlannedRequest], sim: SimResult,
         if tr.enabled and trun is not None:
             tr.amend(rsp, backend="tensor", jit_hits=trun.jit_hits,
                      jit_misses=trun.jit_misses, fell_back=trun.fell_back)
-    residual_jit = None
-    if trun is not None:
-        residual_jit = {"hits": trun.jit_hits, "misses": trun.jit_misses,
-                        "fell_back": trun.fell_back,
-                        "observed": trun.observed,
-                        "n_stages": trun.n_stages}
     t_np = nonpushable_time(split.merged, cfg)
     m = get_metrics()
     m.counter("engine.queries").inc()
@@ -359,7 +353,7 @@ def _run_decided(query: Query, reqs: List[PlannedRequest], sim: SimResult,
         net_bytes_recon=runtime.reconcile_net_bytes(sim, reqs, split),
         outcomes=split.outcomes, recovery=recovery,
         residual_backend=("tensor" if trun is not None else "interpreter"),
-        residual_jit=residual_jit)
+        residual_jit=runtime.residual_jit_info(trun))
 
 
 def run_query(query: Query, catalog: Catalog, cfg: EngineConfig,

@@ -96,6 +96,19 @@ def run_residual(query, merged: Dict[str, ColumnTable],
     return run.table, run
 
 
+def residual_jit_info(trun) -> Optional[Dict]:
+    """The tensor residual's per-run accounting as ``QueryRun.residual_jit``
+    and ``StreamRun.per_query[...]["residual_jit"]`` report it (``None``
+    when the interpreter ran): jit-cache hits/misses, whether the run was
+    the observe pass or a designed fallback, and the platforms of the
+    devices its jitted stages ran on."""
+    if trun is None:
+        return None
+    return {"hits": trun.jit_hits, "misses": trun.jit_misses,
+            "fell_back": trun.fell_back, "observed": trun.observed,
+            "n_stages": trun.n_stages, "platforms": trun.platforms}
+
+
 # --------------------------------------------------------- split execution
 @dataclasses.dataclass
 class RequestOutcome:
@@ -965,9 +978,9 @@ def _run_stream_body(stream, catalog, cfg, time_scale, tr, metrics,
                                            else "interpreter"),
                              jit_hits=(trun.jit_hits if trun else None),
                              jit_misses=(trun.jit_misses if trun else None))
-                return res
+                return res, residual_jit_info(trun)
 
-        result = on_core(merge_and_compute)
+        result, residual_jit = on_core(merge_and_compute)
         sim_pd = sum(r.cost.s_out for r in reqs_by_key[key]
                      if decisions.get(r.req_id, PUSHDOWN) == PUSHDOWN)
         finish_s = time.perf_counter() - t0
@@ -996,7 +1009,8 @@ def _run_stream_body(stream, catalog, cfg, time_scale, tr, metrics,
                 "n_demoted": n_dem, "retries": n_retry, "hedged": n_hedge,
                 "real_net_bytes": pd_b + pb_b,
                 "s_out_estimate_ratio": (sim_pd / pd_b if pd_b else None),
-                "sim_finish": sim.finish_by_query.get(key)}
+                "sim_finish": sim.finish_by_query.get(key),
+                "residual_jit": residual_jit}
 
     finishers: Dict[str, Future] = {}
     errors: Dict[str, BaseException] = {}

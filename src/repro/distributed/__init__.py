@@ -1,6 +1,4 @@
-from repro.distributed import sharding  # noqa: F401
-
-# NOTE: `workers` (the multi-process storage tier) is intentionally NOT
-# imported here — it pulls in multiprocessing/socket machinery that every
-# in-process engine path should stay free of. Import it explicitly:
-#     from repro.distributed.workers import WorkerPool, pool_for
+# Submodules are imported explicitly (``from repro.distributed import
+# sharding``): the package itself imports nothing, so the storage workers
+# (``workers``, which pulls in multiprocessing/socket machinery every
+# in-process engine path stays free of) start without JAX.

@@ -21,7 +21,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 # ---------------------------------------------------------- EP dispatch

@@ -3,8 +3,9 @@
 ``runtime.run_stream`` historically *simulated* storage nodes as thread
 pools inside one process — the Arbitrator reacted to simulator slot
 counts, not actual storage-side pressure. This module splits the storage
-layer into real **storage-worker processes** (one per catalog node, forked
-``multiprocessing`` children talking over a socketpair), each owning the
+layer into real **storage-worker processes** (one per catalog node, spawned
+``multiprocessing`` children talking over a socketpair and pinned off the
+accelerator), each owning the
 disjoint partition set of its node:
 
 - the compute layer dispatches compiled ``PushPlan``s **over the wire**
@@ -54,6 +55,7 @@ import queue
 import signal
 import socket
 import struct
+import sys
 import threading
 import time
 import types
@@ -74,6 +76,7 @@ __all__ = ["WorkerPool", "pool_for", "close_all_pools",
            "encode_plan", "decode_plan"]
 
 _U32 = struct.Struct("<I")
+_STARTUP_TIMEOUT_S = 120.0   # spawn -> first served request
 
 
 # ------------------------------------------------------------- wire framing
@@ -241,13 +244,20 @@ def decode_plan(spec: bytes):
 
 
 # ----------------------------------------------------------- worker process
-def _worker_entry(child_sock: socket.socket, parent_sock: socket.socket,
-                  node_id: int, slots: int) -> None:
-    try:
-        parent_sock.close()   # our inherited copy of the parent's end:
-        # while it stays open here, the parent would never see EOF
-    except OSError:
-        pass
+def _pin_off_accelerator() -> None:
+    """Keep a storage worker on the host: it runs numpy plans only, and a
+    chip belongs to one process — the compute process that holds it. The
+    worker's JAX (imported with the package) may then only ever bring up
+    the CPU backend, so it can neither load libtpu nor claim the chip."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_platforms", "cpu")
+
+
+def _worker_entry(child_sock: socket.socket, node_id: int,
+                  slots: int) -> None:
+    _pin_off_accelerator()
     _WorkerServer(child_sock, node_id, slots).run()
 
 
@@ -449,10 +459,12 @@ class WorkerChannel:
         self.node = node_id
         self.timeout_s = timeout_s
         parent_sock, child_sock = socket.socketpair()
-        ctx = multiprocessing.get_context("fork")
+        # spawn, not fork: a fork would copy a parent that may already hold
+        # the TPU client (its libtpu state and device handles) into every
+        # worker; a spawned interpreter inherits only the socket it is given
+        ctx = multiprocessing.get_context("spawn")
         self.proc = ctx.Process(target=_worker_entry,
-                                args=(child_sock, parent_sock, node_id,
-                                      slots),
+                                args=(child_sock, node_id, slots),
                                 daemon=True)
         self.proc.start()
         child_sock.close()
@@ -466,6 +478,9 @@ class WorkerChannel:
         self.bytes_recv = 0
         self.last_load: Optional[Dict] = None
         threading.Thread(target=self._read_loop, daemon=True).start()
+        # a spawned interpreter takes a moment to import its way to the
+        # serve loop: wait for it once, outside the per-request timeout
+        self.request({"kind": "poll"}, timeout=_STARTUP_TIMEOUT_S)
 
     def _read_loop(self) -> None:
         try:
@@ -545,9 +560,9 @@ class WorkerChannel:
 class WorkerPool:
     """One storage-worker process per catalog node.
 
-    Construction forks the workers and ships each node's partitions over
-    the wire (so the tier exercises the codec end to end, independent of
-    the fork's memory inheritance). ``execute_group``/``fetch_projection``
+    Construction spawns the workers and ships each node's partitions over
+    the wire (the tier exercises the codec end to end; a spawned worker
+    inherits no memory from the parent). ``execute_group``/``fetch_projection``
     are the two tier entry points ``core.runtime`` dispatches through;
     both re-ship any partition whose catalog version moved since the last
     ship (append/update staleness), publish the worker's load snapshot

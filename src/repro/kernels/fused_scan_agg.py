@@ -19,11 +19,13 @@ both the value vector and the count contraction, so SUM semantics are exact
 from __future__ import annotations
 
 import functools
-from typing import Callable, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.predicate_bitmap import resolve_interpret
 
 DEFAULT_BLOCK = 8192
 
@@ -55,7 +57,7 @@ def _kernel(pred_fn: Callable, names: Sequence[str], num_groups: int, *refs):
 
 def fused_scan_agg(cols, pred_fn: Callable, ids: jax.Array, values: jax.Array,
                    num_groups: int, block: int = DEFAULT_BLOCK,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """cols: dict of equal-length 1-D predicate input arrays; ids: (R,)
     int32 in [0, num_groups); values: (R,). R % block == 0.
     Returns (sums (G,) f32, counts (G,) int32) over rows passing pred_fn.
@@ -76,5 +78,5 @@ def fused_scan_agg(cols, pred_fn: Callable, ids: jax.Array, values: jax.Array,
                    pl.BlockSpec((num_groups,), lambda i: (0,))],
         out_shape=[jax.ShapeDtypeStruct((num_groups,), jnp.float32),
                    jax.ShapeDtypeStruct((num_groups,), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*arrs, ids, values)

@@ -16,10 +16,13 @@ paper's two-phase S3-Select workaround — except one phase here is free.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.predicate_bitmap import resolve_interpret
 
 DEFAULT_BLOCK = 8192
 
@@ -46,7 +49,7 @@ def _kernel(num_groups: int, ids_ref, val_ref, sum_ref, cnt_ref):
 
 
 def grouped_agg(ids: jax.Array, values: jax.Array, num_groups: int,
-                block: int = DEFAULT_BLOCK, interpret: bool = True):
+                block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """ids: (R,) int32 in [0, num_groups); values: (R,).
     Returns (sums (G,) f32, counts (G,) int32). R % block == 0."""
     R = ids.shape[0]
@@ -61,5 +64,5 @@ def grouped_agg(ids: jax.Array, values: jax.Array, num_groups: int,
                    pl.BlockSpec((num_groups,), lambda i: (0,))],
         out_shape=[jax.ShapeDtypeStruct((num_groups,), jnp.float32),
                    jax.ShapeDtypeStruct((num_groups,), jnp.int32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids, values)

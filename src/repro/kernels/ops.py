@@ -2,8 +2,10 @@
 
 Handle the unglamorous edges: pad to block multiples (padding rows carry a
 poison group id / always-false predicate so results are exact), dtype
-guards, and un-padding. ``interpret=True`` everywhere on this CPU
-container; on a real TPU the same calls lower natively.
+guards, and un-padding. ``interpret=None`` (the default) compiles each
+kernel with Mosaic on a TPU backend and runs the Pallas interpreter only
+on the CPU backend the tests use; ``tests/test_tpu_compile.py`` compiles
+every wrapper for a described v5e chip.
 """
 from __future__ import annotations
 
@@ -34,7 +36,7 @@ def _pad_to(x: jax.Array, mult: int, fill=0):
 
 
 def predicate_bitmap(cols: Dict[str, jax.Array], pred_fn: Callable,
-                     block: int = DEFAULT_BLOCK, interpret: bool = True):
+                     block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """Packed (ceil(R/32),) uint32 bitmap of pred_fn over the columns.
     Padding rows evaluate through pred_fn but are masked off the result."""
     R = next(iter(cols.values())).shape[0]
@@ -55,16 +57,16 @@ def predicate_bitmap(cols: Dict[str, jax.Array], pred_fn: Callable,
 
 
 def bitmap_apply(words: jax.Array, col: jax.Array,
-                 block: int = DEFAULT_BLOCK, interpret: bool = True):
+                 block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """(masked col (R,), total selected count). Accepts any R."""
     col_p, R = _pad_to(col, block)
     words_p, _ = _pad_to(words, col_p.shape[0] // 32)
-    masked, counts = _ba.bitmap_apply(words_p, col_p, block, interpret)
-    return masked[:R], counts.sum()
+    masked, count = _ba.bitmap_apply(words_p, col_p, block, interpret)
+    return masked[:R], count[0, 0]
 
 
 def grouped_agg(ids: jax.Array, values: jax.Array, num_groups: int,
-                block: int = DEFAULT_BLOCK, interpret: bool = True):
+                block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """(sums (G,) f32, counts (G,) int32); padding rows get id == G and an
     extra scratch group that is dropped."""
     ids_p, R = _pad_to(ids.astype(jnp.int32), block, fill=num_groups)
@@ -76,7 +78,7 @@ def grouped_agg(ids: jax.Array, values: jax.Array, num_groups: int,
 
 def fused_scan_agg(cols: Dict[str, jax.Array], pred_fn: Optional[Callable],
                    ids: jax.Array, values: jax.Array, num_groups: int,
-                   block: int = DEFAULT_BLOCK, interpret: bool = True):
+                   block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """Fused predicate -> mask -> grouped agg: (sums (G,) f32, counts (G,)
     int32) over rows passing pred_fn. Padding rows carry the poison group
     id == G (their one-hot column is an extra scratch group, dropped), so
@@ -95,7 +97,7 @@ def fused_scan_agg(cols: Dict[str, jax.Array], pred_fn: Optional[Callable],
 
 def fused_scan_shuffle(cols: Dict[str, jax.Array], pred_fn: Optional[Callable],
                        keys: jax.Array, num_parts: int,
-                       block: int = DEFAULT_BLOCK, interpret: bool = True):
+                       block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """Fused predicate -> packed bitmap -> hash partition: (packed bitmap
     (ceil(R/32),) uint32, pids (R,) int32, surviving-rows-per-target hist
     (P,) int32) in one pass. A validity lane zeroes padding rows inside the
@@ -113,17 +115,16 @@ def fused_scan_shuffle(cols: Dict[str, jax.Array], pred_fn: Optional[Callable],
                                                 valid_p, num_parts, block,
                                                 interpret)
     n_words = -(-R // 32)
-    return (words[:n_words] if R else words[:0], pids[:R],
-            hist.sum(axis=0))
+    return (words[:n_words] if R else words[:0], pids[:R], hist[0])
 
 
 def hash_partition(keys: jax.Array, num_parts: int,
-                   block: int = DEFAULT_BLOCK, interpret: bool = True):
+                   block: int = DEFAULT_BLOCK, interpret: Optional[bool] = None):
     """(pids (R,) int32, hist (P,) int32). Padding keys hash somewhere but
     are excluded from the histogram by subtraction."""
     keys_p, R = _pad_to(keys, block)
     pids, hist = _hp.hash_partition(keys_p, num_parts, block, interpret)
-    hist = hist.sum(axis=0)
+    hist = hist[0]
     pad = keys_p.shape[0] - R
     if pad:
         pad_pids = pids[R:]

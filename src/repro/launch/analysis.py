@@ -156,8 +156,6 @@ def cpu_upcast_bytes(hlo_text: str, min_bytes: int = 1 << 26) -> int:
 # -------------------------------------------------------------- extraction
 def cost_summary(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [dict]
-        ca = ca[0]
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}
 

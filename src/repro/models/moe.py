@@ -112,7 +112,7 @@ def apply_moe_ep(x: jax.Array, prm: dict, cfg: ModelConfig):
 
     Returns (None, None) when the mesh doesn't apply (falls back to dense).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import constraints, sharding as shd
@@ -187,7 +187,7 @@ def apply_moe_ep(x: jax.Array, prm: dict, cfg: ModelConfig):
         in_specs=(bspec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(bspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, prm["router"], prm["w_gate"], prm["w_up"], prm["w_out"])
     if cfg.num_shared_experts > 0:
         y = y + apply_mlp(x.reshape(-1, d), prm["shared"], cfg).reshape(x.shape)

@@ -4,8 +4,8 @@
 ``expressions.compile_expr`` lowers, into a closure over a dict of
 **jax** arrays (or tracers): same tree walk, same association order, the
 numpy ufuncs swapped for their ``jax.numpy`` twins. Under x64
-(``jax.experimental.enable_x64``) the results match the numpy closure
-bitwise — ``compiler/tensorize.py`` relies on this to evaluate residual
+(``jax.enable_x64(True)``) the results match the numpy closure
+bitwise on the CPU backend — ``compiler/tensorize.py`` relies on this to evaluate residual
 Filter predicates inside a ``jax.jit``-traced program, and
 ``tests/test_tensorize.py`` pins the equivalence on random columns.
 

@@ -107,11 +107,12 @@ def test_steps_lower_on_small_mesh(arch, shape):
     cfg = get_config("{arch}", reduced=True)
     shape = dataclasses.replace(get_shape("{shape}"), global_batch=8,
                                 seq_len=256, accum=2)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     b = steps.build(cfg, shape, mesh)
     with mesh:
         c = b.lower().compile()
-    from repro.launch.analysis import cost_summary  # list/dict-safe
+    from repro.launch.analysis import cost_summary
     print("compiled", cost_summary(c)["flops"] > 0)
     """)
     assert "compiled True" in out

@@ -22,6 +22,7 @@ from repro.compiler import (compile_query, compile_query_detailed,
 from repro.compiler.tpch_ir import QUERY_IDS
 from repro.core import engine, runtime
 from repro.core.arbitrator import PUSHBACK, PUSHDOWN
+from repro.obs import metrics as om
 from repro.queryproc import expressions as ex
 from repro.queryproc import tpch
 from repro.queryproc.expressions import Col
@@ -30,6 +31,16 @@ from repro.queryproc.table import ColumnTable
 
 CAT = tpch.build_catalog(sf=0.5, num_nodes=2, rows_per_partition=4_000)
 CFG = engine.EngineConfig(mode="eager")
+
+
+@pytest.fixture
+def metrics():
+    """A fresh registry, so a test reads only its own residual counters."""
+    prev = om.get_metrics()
+    m = om.Metrics()
+    om.set_metrics(m)
+    yield m
+    om.set_metrics(prev)
 
 
 def merged_for(cq):
@@ -44,9 +55,11 @@ def merged_for(cq):
 
 # ------------------------------------------------ all-15 oracle identity
 @pytest.mark.parametrize("qid", QUERY_IDS)
-def test_tensor_matches_interpreter(qid):
+def test_tensor_matches_interpreter(qid, metrics):
     """observe -> first jit (miss) -> warm (hit): all three runs return
-    the interpreter's exact table, and the warm run hits every stage."""
+    the interpreter's exact table, and the warm run hits every stage —
+    on the jitted path itself: no error and no fallback was counted, so a
+    residual that silently settled on the oracle cannot pass."""
     cq = compile_query_detailed(qid)
     merged = merged_for(cq)
     ref = interpreter.run(cq.residual, merged)
@@ -62,6 +75,34 @@ def test_tensor_matches_interpreter(qid):
     assert r_cold.jit_hits == 0 and r_cold.jit_misses >= 1
     assert r_warm.jit_misses == 0
     assert r_warm.jit_hits == r_cold.jit_misses
+    assert r_warm.platforms == ("cpu",)
+    assert metrics.counter("residual.errors").value == 0
+    assert metrics.counter("residual.fallbacks").value == 0
+
+
+def test_stage_error_raises_instead_of_falling_back(monkeypatch, metrics):
+    """Only the designed guards (``TensorFallback``) replay the oracle. Any
+    other failure inside a stage — a lowering, compile or device error —
+    raises, counts in ``residual.errors`` and not as a fallback, and leaves
+    the residual on the jitted path for the next run."""
+    res = _agg_residual()
+    merged = {"t": _tab(np.arange(64) % 4)}
+    tensorize.execute(res, merged)                   # observe
+    art = tensorize._artifact(res)
+
+    def broken(inputs):
+        raise RuntimeError("stage failed to compile")
+
+    monkeypatch.setattr(art, "jit_fns", art.jit_fns[:-1] + [broken])
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        tensorize.execute(res, merged)
+    assert metrics.counter("residual.errors").value == 1
+    assert metrics.counter("residual.fallbacks").value == 0
+    assert not art.disabled
+    monkeypatch.undo()
+    ok = tensorize.execute(res, merged)
+    assert not ok.fell_back
+    assert engine.results_equal(interpreter.run(res, merged), ok.table)
 
 
 def test_pyop_queries_partition_into_two_stages():
@@ -125,6 +166,28 @@ def test_fault_demoted_replay_identical(monkeypatch):
     assert engine.results_equal(clean.result, run.result)
 
 
+def test_stream_on_process_tier_jits_residuals():
+    """The served path: ``run_stream`` with storage-worker processes and
+    the tensor residual. Workers are spawned pinned off the accelerator
+    while this process holds JAX; the second stream's residuals run the
+    jitted stages, and every answer matches the interpreter."""
+    from repro.distributed import workers
+    qs = [compile_query(q) for q in ("Q1", "Q5", "Q14")]
+    cfg = engine.EngineConfig(residual="tensor", storage_tier="process")
+    try:
+        for _ in range(2):                       # observe, then jitted
+            run = runtime.run_stream(
+                [runtime.StreamQuery(q, arrival=0.0) for q in qs], CAT, cfg,
+                time_scale=0.0)
+    finally:
+        workers.close_all_pools()
+    for q in qs:
+        want = engine.run_query(q, CAT, CFG).result
+        assert engine.results_equal(want, run.results[q.qid]), q.qid
+        info = run.per_query[q.qid]["residual_jit"]
+        assert info["platforms"] == ("cpu",) and not info["fell_back"], q.qid
+
+
 # ------------------------------------------------- engine accounting/auto
 def test_queryrun_jit_accounting():
     q = compile_query("Q14")
@@ -137,6 +200,8 @@ def test_queryrun_jit_accounting():
     assert r3.residual_jit["hits"] == r3.residual_jit["n_stages"]
     assert r3.residual_jit["misses"] == 0
     assert not r3.residual_jit["fell_back"]
+    assert r1.residual_jit["platforms"] == ()        # observe: host only
+    assert r3.residual_jit["platforms"] == ("cpu",)
 
 
 def test_auto_mode_threshold(monkeypatch):
@@ -224,6 +289,33 @@ def test_respecialize_on_domain_growth():
     assert engine.results_equal(interpreter.run(res, big), r_ok.table)
 
 
+@pytest.mark.parametrize("dtype,spec_len", [(np.int64, 3), (np.float64, 1)])
+def test_huge_domain_aggregate_sorts(dtype, spec_len):
+    """Group keys beyond the code-domain cap sort: integral keys as one
+    packed code over their observed bounds (keys leaving them
+    respecialize, as on the code path), other keys by lexsort. Both
+    match the interpreter."""
+    rng = np.random.default_rng(3)
+    res = ir.Aggregate(ir.Merged("t"), ("a", "b"),
+                       (("s", "sum", "v"), ("c", "count", "v")))
+
+    def tab(lo):
+        return {"t": ColumnTable({
+            "a": rng.integers(lo, lo + 4_000, 3_000).astype(dtype),
+            "b": rng.integers(0, 1_000, 3_000).astype(dtype),
+            "v": rng.normal(size=3_000)})}
+
+    first, wider = tab(0), tab(10_000)
+    tensorize.execute(res, first)                    # observe
+    art = tensorize._artifact(res)
+    assert len(art.obs["agg"][id(res)]) == spec_len
+    for merged in (first, wider, wider):
+        run = tensorize.execute(res, merged)
+        assert engine.results_equal(interpreter.run(res, merged), run.table)
+    assert not run.fell_back
+    assert art.gen == (1 if spec_len == 3 else 0)
+
+
 def test_shape_buckets_share_jitted_programs():
     """Row counts in the same pow-2 bucket reuse the compiled program;
     crossing a bucket boundary compiles once more, results identical."""
@@ -285,10 +377,43 @@ def test_empty_build_side():
     assert engine.results_equal(ref, run.table)
 
 
+# ------------------------------------------------ persistent compile cache
+@pytest.mark.parametrize("backend,outside", [("cpu", False), ("tpu", False),
+                                             ("tpu", True)])
+def test_compile_cache_placement(monkeypatch, tmp_path, backend, outside):
+    """On an accelerator the cache goes where ``JAX_COMPILATION_CACHE_DIR``
+    (read by JAX into ``jax_compilation_cache_dir``) says, else to the
+    fixed checkout directory, with thresholds that keep every residual
+    stage; on the CPU backend nothing is changed."""
+    import jax
+    from repro import jaxcache
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.setattr(jaxcache, "_DONE", False)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    try:
+        if outside:
+            jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        path = jaxcache.enable_compile_cache()
+        after = {n: getattr(jax.config, n) for n in names}
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)
+    if backend == "cpu":
+        assert after == before and path == before[names[0]]
+    else:
+        assert path == (str(tmp_path) if outside
+                        else jaxcache.CHECKOUT_CACHE_DIR)
+        assert path == after["jax_compilation_cache_dir"]
+        assert after["jax_persistent_cache_min_compile_time_secs"] == 0
+        assert after["jax_persistent_cache_min_entry_size_bytes"] == -1
+
+
 # --------------------------------------------- expression twin equivalence
 def test_compile_expr_jnp_matches_numpy():
     import jax
-    from jax.experimental import enable_x64
     rng = np.random.default_rng(11)
     cols = {"a": rng.integers(0, 50, 400).astype(np.int64),
             "b": rng.normal(size=400),
@@ -300,7 +425,7 @@ def test_compile_expr_jnp_matches_numpy():
         Col("c").isin((1, 3, 4)) & (Col("a") > 5),
         (Col("a") <= Col("a")) & Col("c").isin((0,)),
     ]
-    with enable_x64():
+    with jax.enable_x64(True):
         for e in exprs:
             want = ex.compile_expr(e)(cols)
             jf = jax.jit(compile_expr_jnp(e))
