@@ -87,6 +87,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import threading
 import time
 from collections import OrderedDict
@@ -260,6 +261,7 @@ class _Artifact:
     respecs: int = 0
     lock: threading.Lock = dataclasses.field(default_factory=threading.Lock)
     disabled: bool = False           # respec cap reached: oracle-only
+    qid: Optional[str] = None        # names the jitted stages
 
 
 @dataclasses.dataclass
@@ -516,6 +518,13 @@ def _build_jits(art: _Artifact) -> None:
     art.seen = set()
 
 
+def stage_name(qid: Optional[str], index: int) -> str:
+    """The jitted stage's function name, so that its XLA module and its
+    profiler events read ``jit_residual_Q18_s0``."""
+    q = re.sub(r"\W", "_", qid) if qid else ""
+    return f"residual_{q}_s{index}" if q else f"residual_s{index}"
+
+
 def _make_stage_fn(stage: _Stage, art: _Artifact) -> Callable:
     def stage_fn(inputs):
         import jax.numpy as jnp
@@ -533,6 +542,8 @@ def _make_stage_fn(stage: _Stage, art: _Artifact) -> Callable:
             resp = resp | f
         return {"outs": outs, "fallback": flag, "respec": resp}
 
+    stage_fn.__name__ = stage_fn.__qualname__ = stage_name(art.qid,
+                                                           stage.index)
     return stage_fn
 
 
@@ -982,7 +993,8 @@ def _unpad(out: Dict) -> ColumnTable:
 
 
 def _observe_run(art: _Artifact, residual: ir.Node,
-                 merged: Dict[str, ColumnTable]) -> TensorRun:
+                 merged: Dict[str, ColumnTable],
+                 qid: Optional[str]) -> TensorRun:
     """First execute of a residual: run the instrumented oracle, record
     aggregate key bounds / join LUT feasibility from its memo, and build
     the specialized jit fns. The oracle's table is this run's result."""
@@ -994,6 +1006,7 @@ def _observe_run(art: _Artifact, residual: ir.Node,
         memo: Dict[int, ColumnTable] = {}
         result = interpreter._run(residual, merged, memo)
         _observe(art, memo)
+        art.qid = qid
         _build_jits(art)
         if tr.enabled:
             sp.set(n_stages=len(art.stages),
@@ -1026,13 +1039,24 @@ def _respecialize(art: _Artifact, residual: ir.Node,
         return result
 
 
-def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
+def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
+            qid: Optional[str] = None) -> TensorRun:
     """Run a residual through the tensor backend. Results are identical to
     ``interpreter.run`` (the oracle); on a lowering-guard trip
     (``TensorFallback``) the oracle is replayed host-side and ``fell_back``
     is set. Any other error — a lowering, compile or device failure —
     counts in ``residual.errors`` and raises: it never turns into a
-    silent interpreter run."""
+    silent interpreter run. ``qid`` names the jitted stages the first
+    call builds (``stage_name``).
+
+    Each jitted stage call runs in four steps, each a child span of the
+    caller's (attrs ``qid``, ``stage``, ``hit``): ``residual_prep``
+    (host prep chains, bucket padding, join LUTs), ``residual_h2d`` (one
+    ``jax.device_put`` of the inputs; traced, it waits for the copy),
+    ``residual_device`` (the jit call until its guard flags are on the
+    host: device work plus any wait behind other callers) and
+    ``residual_d2h`` (readback). ``residual.h2d_bytes`` counts the bytes
+    put on the device."""
     import jax
     from repro.compiler import interpreter
 
@@ -1046,7 +1070,7 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
     if art.obs is None:
         with art.lock:
             if art.obs is None:
-                return _observe_run(art, residual, merged)
+                return _observe_run(art, residual, merged, qid)
 
     hits = misses = 0
     env: Dict[str, ColumnTable] = {}        # PyOp stage outputs
@@ -1055,6 +1079,7 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
     result: Optional[ColumnTable] = None
     fell_back = False
     platforms: set = set()
+    h2d = m.counter("residual.h2d_bytes")
 
     def host_tab(name: str) -> ColumnTable:
         t = env.get(name)
@@ -1071,40 +1096,49 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable]) -> TensorRun:
             for st in art.stages:
                 out_tabs: Dict[int, ColumnTable] = {}
                 if st.jit_roots:
-                    inputs: Dict = {}
-                    key: Tuple = (st.index, art.gen)
-                    for name in st.names:
-                        inputs[name], sig = _pad_table(host_tab(name))
-                        key += (name,) + sig
-                    for jname, rname, rkey, is_join in st.luts:
-                        lut, kmin = _build_lut(host_tab(rname), rkey,
-                                               is_join)
-                        inputs[jname] = {"lut": lut, "kmin": kmin}
-                        key += (jname, lut.shape[0])
-                    stage_hit = key in art.seen
-                    if stage_hit:
+                    attrs = {"qid": qid, "stage": st.index}
+                    with tr.span("residual_prep", "compiler", **attrs) as sp:
+                        inputs: Dict = {}
+                        key: Tuple = (st.index, art.gen)
+                        for name in st.names:
+                            inputs[name], sig = _pad_table(host_tab(name))
+                            key += (name,) + sig
+                        for jname, rname, rkey, is_join in st.luts:
+                            lut, kmin = _build_lut(host_tab(rname), rkey,
+                                                   is_join)
+                            inputs[jname] = {"lut": lut, "kmin": kmin}
+                            key += (jname, lut.shape[0])
+                        attrs["hit"] = key in art.seen
+                        sp.set(hit=attrs["hit"])
+                    if attrs["hit"]:
                         hits += 1
                     else:
                         misses += 1
                         art.seen.add(key)
-                    t0 = time.perf_counter()
-                    out = art.jit_fns[st.index](inputs)
-                    if bool(out["respec"]):
+                    with tr.span("residual_h2d", "compiler", **attrs):
+                        h2d.inc(sum(a.nbytes for a in
+                                    jax.tree_util.tree_leaves(inputs)))
+                        dev_inputs = jax.device_put(inputs)
+                        del inputs       # the padded host copies go here
+                        if tr.enabled:
+                            jax.block_until_ready(dev_inputs)
+                    with tr.span("residual_device", "compiler", **attrs):
+                        out = art.jit_fns[st.index](dev_inputs)
+                        respec = bool(out["respec"])
+                        fallback = bool(out["fallback"])
+                    if respec:
                         raise TensorFallback(
                             "aggregate keys left the observed domain",
                             respec=True)
-                    if bool(out["fallback"]):
+                    if fallback:
                         raise TensorFallback(f"stage {st.index}")
-                    platforms.update(d.platform for a in
-                                     jax.tree_util.tree_leaves(out)
-                                     for d in a.devices())
-                    if tr.enabled:
-                        tr.event("residual_jit_cache", cat="compiler",
-                                 stage=st.index, hit=stage_hit,
-                                 ms=round(1e3 * (time.perf_counter() - t0),
-                                          3))
-                    for root, o in zip(st.jit_roots, out["outs"]):
-                        out_tabs[id(root)] = _unpad(o)
+                    with tr.span("residual_d2h", "compiler", **attrs):
+                        platforms.update(d.platform for a in
+                                         jax.tree_util.tree_leaves(out)
+                                         for d in a.devices())
+                        for root, o in zip(st.jit_roots, out["outs"]):
+                            out_tabs[id(root)] = _unpad(o)
+                        del out, dev_inputs      # frees the device buffers
                 if st.pyop is not None:
                     tables = [out_tabs[id(r)] if id(r) in out_tabs
                               else host_tab(art.leaf_names[id(r)])

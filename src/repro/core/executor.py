@@ -156,12 +156,9 @@ def _init_threshold() -> float:
 
 FILTER_GATHER_THRESHOLD = _init_threshold()
 
-# Batch filter-stage decisions now live in the observability subsystem's
-# bounded, thread-safe channel (repro.obs.filter_decision_channel) — the
-# old FILTER_DECISIONS module list grew without bound across runs and
-# raced under run_stream's thread pools. These wrappers keep the public
-# surface; FILTER_DECISIONS itself survives one release as a deprecated
-# read-only snapshot via the module __getattr__ below.
+# Batch filter-stage decisions live in the observability subsystem's
+# bounded, thread-safe channel (repro.obs.filter_decision_channel); these
+# wrappers are the executor's surface over it.
 
 
 def reset_filter_decisions() -> None:
@@ -177,13 +174,6 @@ def filter_decision_counts() -> Dict[str, int]:
 def _record_decision(table: str, est: Optional[float], branch: str,
                      n_parts: int, rows: int) -> None:
     obs_trace.record_filter_decision(table, est, branch, n_parts, rows)
-
-
-def __getattr__(name: str):
-    if name == "FILTER_DECISIONS":
-        # deprecated alias (one release): read-only snapshot of the channel
-        return obs_trace.filter_decision_channel().snapshot()
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 @dataclasses.dataclass

@@ -92,7 +92,7 @@ def run_residual(query, merged: Dict[str, ColumnTable],
         rows = sum(len(t) for t in merged.values())
         if rows < tensorize.auto_threshold():
             return query.compute(merged), None
-    run = tensorize.execute(residual, merged)
+    run = tensorize.execute(residual, merged, query.qid)
     return run.table, run
 
 

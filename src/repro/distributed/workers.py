@@ -311,6 +311,9 @@ class _WorkerServer:
                                  "seconds": header["seconds"]}, b""))
                 self._reply({"req": header["req"], "ok": True})
             else:                       # exec | fetch — the work queue
+                if header.get("trace"):
+                    # perf_counter is CLOCK_MONOTONIC, the parent's clock
+                    header["t_recv"] = time.perf_counter()
                 with self.lock:
                     self.pending["exec" if kind == "exec" else "fetch"] += 1
                 self.q.put((header, body))
@@ -318,6 +321,8 @@ class _WorkerServer:
     def _work(self) -> None:   # pragma: no cover — child process threads
         while True:
             header, body = self.q.get()
+            if header.get("trace"):
+                header["t_start"] = time.perf_counter()
             kind = header["kind"]
             with self.lock:
                 if self.die_after is not None and self.done >= self.die_after:
@@ -394,7 +399,6 @@ class _WorkerServer:
         cplan = self._compiled(header, cur)
         bms = _dec(header["bms"], cur) if "bms" in header else None
         tabs = self._tabs(header)
-        t0 = time.perf_counter()
         if header["executor"] == EXECUTOR_REFERENCE:
             out = [execute_push_plan(cplan.plan, t,
                                      None if bms is None else bms[i])
@@ -403,34 +407,40 @@ class _WorkerServer:
             parts_res, aux = cplan.execute_batch_parts(
                 tabs, bms, header.get("threshold"))
             out = list(zip(parts_res, aux))
-        dur = time.perf_counter() - t0
         bufs: List[bytes] = []
         vals = _enc([[res, aux] for res, aux in out], bufs)
-        spans = self._spans(header, "worker_execute", dur, tabs, out)
+        spans = self._spans(header, "worker_execute", tabs, out)
         return {"vals": vals}, bufs, spans
 
     def _fetch(self, header: Dict, body) -> Tuple[Dict, List[bytes], List]:
         cur = _Cursor(body)
         cplan = self._compiled(header, cur)
         tabs = self._tabs(header)
-        t0 = time.perf_counter()
         projs = [cplan.raw_projection(t) for t in tabs]
-        dur = time.perf_counter() - t0
         bufs: List[bytes] = []
         vals = _enc(projs, bufs)
-        spans = self._spans(header, "worker_fetch", dur, tabs, None)
+        spans = self._spans(header, "worker_fetch", tabs, None)
         return {"vals": vals}, bufs, spans
 
-    def _spans(self, header: Dict, name: str, dur: float, tabs,
+    def _spans(self, header: Dict, name: str, tabs,
                out) -> Optional[List[Dict]]:
+        """The traced request's spans on the shared monotonic clock: the
+        wait in this worker's queue (frame read to a slot's pickup) and
+        the handling (pickup to the reply's encoded body)."""
         if not header.get("trace"):
             return None
+        t_end = time.perf_counter()
         attrs = {"node": self.node, "pid": os.getpid(),
                  "table": header["parts"][0][0], "n_parts": len(tabs)}
         if out is not None:
             attrs["rows_out"] = int(sum(len(res) for res, _ in out))
-        return [{"name": name, "t0": 0.0, "dur": dur,
-                 "remote_parent": header.get("span"), "attrs": attrs}]
+        parent = header.get("span")
+        t_recv, t_start = header["t_recv"], header["t_start"]
+        return [{"name": "worker_queue", "t0": t_recv,
+                 "dur": t_start - t_recv, "remote_parent": parent,
+                 "attrs": {"node": self.node, "pid": attrs["pid"]}},
+                {"name": name, "t0": t_start, "dur": t_end - t_start,
+                 "remote_parent": parent, "attrs": attrs}]
 
     def _load_snapshot(self) -> Dict:
         with self.lock:
@@ -646,14 +656,13 @@ class WorkerPool:
             if tr.enabled:
                 header["trace"] = True
                 header["span"] = parent.sid if parent is not None else None
-            t_send = time.perf_counter()
             rh, rb = self.channels[node].request(header, b"".join(bufs))
             if spec is not None:
                 self._shipped_plans[node].add(key)
             out = [(res, aux) for res, aux in _dec(rh["vals"], _Cursor(rb))]
             get_metrics().counter("wire.pushdown_result_bytes").inc(len(rb))
             self._publish(node, rh.get("load"))
-            self._adopt(tr, rh.get("spans"), parent, t_send)
+            self._adopt(tr, rh.get("spans"), parent)
             return out
         except _faults.WorkerFault as wf:
             self._record_fault(wf, table=sub[0].table, op="exec")
@@ -681,14 +690,13 @@ class WorkerPool:
             if tr.enabled:
                 header["trace"] = True
                 header["span"] = parent.sid if parent is not None else None
-            t_send = time.perf_counter()
             rh, rb = self.channels[node].request(header, b"".join(bufs))
             if spec is not None:
                 self._shipped_plans[node].add(key)
             tabs = _dec(rh["vals"], _Cursor(rb))
             get_metrics().counter("wire.pushback_ship_bytes").inc(len(rb))
             self._publish(node, rh.get("load"))
-            self._adopt(tr, rh.get("spans"), parent, t_send)
+            self._adopt(tr, rh.get("spans"), parent)
             return tabs
         except _faults.WorkerFault as wf:
             self._record_fault(wf, table=sub[0].table, op="fetch")
@@ -722,22 +730,20 @@ class WorkerPool:
                 out[node] = None
         return out
 
-    def _adopt(self, tr, recs, parent, t_send: float) -> None:
+    def _adopt(self, tr, recs, parent) -> None:
         """Stitch worker-side span records into the compute-side trace:
         each record becomes a real span parented under the dispatching
-        span, its clock mapped onto the send timestamp (wire latency is
-        absorbed into the offset — the worker reports t0 relative to its
-        own handling start)."""
+        span, placed at the absolute times the worker read off the
+        shared monotonic clock (``time.perf_counter``)."""
         if not recs or not tr.enabled:
             return
-        base = t_send - tr.t0
         for rec in recs:
             sp = tr.start(rec["name"], cat="worker", parent=parent,
                           **rec.get("attrs", {}))
             if sp is obs_trace.NULL_SPAN:
                 continue
             sp.attrs["remote_parent"] = rec.get("remote_parent")
-            sp.t0 = base + float(rec.get("t0") or 0.0)
+            sp.t0 = float(rec["t0"]) - tr.t0
             tr.end(sp)
             sp.dur = float(rec.get("dur") or 0.0)
             tr.amend(sp)   # re-emit: a streaming sink saw the wrong dur

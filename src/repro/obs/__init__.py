@@ -13,7 +13,7 @@ exporter usage. Quickstart::
     print(export.summary_table(tr))
 """
 from repro.obs.trace import (
-    DecisionChannel, NULL_TRACER, Span, Tracer,
+    DecisionChannel, NULL_TRACER, PROFILER_PREFIX, Span, Tracer,
     filter_decision_channel, get_tracer, record_filter_decision,
     set_tracer, tracing,
 )
@@ -23,7 +23,7 @@ from repro.obs.metrics import (
 from repro.obs import export
 
 __all__ = [
-    "Span", "Tracer", "DecisionChannel", "NULL_TRACER",
+    "Span", "Tracer", "DecisionChannel", "NULL_TRACER", "PROFILER_PREFIX",
     "get_tracer", "set_tracer", "tracing",
     "record_filter_decision", "filter_decision_channel",
     "Counter", "Gauge", "Histogram", "Metrics",

@@ -6,10 +6,15 @@ replay -> merge — but tracing is OFF by default: every hook routes through
 the module-level tracer, and the default :data:`NULL_TRACER` turns each
 ``tracer.span(...)`` / ``tracer.event(...)`` / ``tracer.start(...)`` call
 into a constant-time no-op (a shared context manager yielding a shared
-null span whose ``set()`` swallows everything). The benchmarked bound —
-enforced by ``benchmarks.perf_guard`` over ``BENCH_engine.json`` — is
-that even *enabled* tracing costs < 2% wall-clock on the sf=1
-all-queries suite (``benchmarks.obs_overhead``).
+null span whose ``set()`` swallows everything). What *enabled* tracing
+costs is measured on the chip, end to end: see ``docs/observability.md``.
+
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` named
+``PROFILER_PREFIX + name`` around every same-thread ``span(...)``, so a
+JAX profiler trace taken meanwhile carries the engine's spans on the
+profiler's own clock, on the thread that ran them. Only when JAX is
+already imported: a process without JAX runs no profiler. Detached
+(``start``/``end``) and adopted spans stay on the host clock only.
 
 Span parenting is thread-aware: within one thread, ``tracer.span(...)``
 context managers nest via a thread-local stack; across thread boundaries
@@ -17,10 +22,8 @@ context managers nest via a thread-local stack; across thread boundaries
 explicitly — pool workers share no context, so implicit propagation would
 silently mis-parent.
 
-``DecisionChannel`` is the bounded, thread-safe event log that replaces
-the old ``core.executor.FILTER_DECISIONS`` module global (which grew
-unboundedly across runs and raced under the stream driver's pools): a
-capped list behind a lock, with ``snapshot()``/``counts()`` readers. One
+``DecisionChannel`` is the bounded, thread-safe event log: a capped
+list behind a lock, with ``snapshot()``/``counts()`` readers. One
 module-level channel records the batch executor's gather-vs-concat filter
 decisions regardless of tracing (the benchmarks report them); each
 ``Tracer`` additionally owns an arbitration channel the Arbitrator feeds
@@ -31,12 +34,13 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import sys
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional
 
 __all__ = [
-    "Span", "Tracer", "DecisionChannel", "NULL_TRACER",
+    "Span", "Tracer", "DecisionChannel", "NULL_TRACER", "PROFILER_PREFIX",
     "get_tracer", "set_tracer", "tracing",
     "record_filter_decision", "filter_decision_channel",
 ]
@@ -107,6 +111,20 @@ class _NullCM:
 
 
 _NULL_CM = _NullCM()
+
+# the name prefix of the profiler annotations an enabled tracer opens
+PROFILER_PREFIX = "repro/"
+
+
+def _annotate(name: str):
+    """An entered ``TraceAnnotation`` for a same-thread span, or None
+    while JAX is not imported (then no profiler can be running)."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return None
+    ann = prof.TraceAnnotation(PROFILER_PREFIX + name)
+    ann.__enter__()
+    return ann
 
 
 class DecisionChannel:
@@ -190,10 +208,10 @@ class DecisionChannel:
 class _SpanCM:
     """Hand-rolled span context manager — a generator-based
     ``@contextmanager`` costs ~4µs per use; at engine span rates that is
-    the difference between fitting the <2% overhead bound and not."""
+    a measurable share of a traced run."""
 
     __slots__ = ("_tr", "_name", "_cat", "_parent", "_attrs", "_sp",
-                 "_stack")
+                 "_stack", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  parent: Optional["Span"], attrs: Dict):
@@ -204,6 +222,7 @@ class _SpanCM:
         self._attrs = attrs
         self._sp: Optional[Span] = None
         self._stack: Optional[List[Span]] = None
+        self._ann = None
 
     def __enter__(self):
         sp = self._tr._new(self._name, self._cat, self._parent, self._attrs)
@@ -212,12 +231,15 @@ class _SpanCM:
         self._sp = sp
         stack = self._stack = self._tr._stack()
         stack.append(sp)
+        self._ann = _annotate(self._name)
         return sp
 
     def __exit__(self, *exc) -> bool:
         sp = self._sp
         if sp is not None:
             sp.dur = time.perf_counter() - self._tr.t0 - sp.t0
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
             stack = self._stack
             if stack and stack[-1] is sp:
                 stack.pop()
@@ -444,8 +466,7 @@ def tracing(tracer: Optional[Tracer] = None) -> Iterator[Tracer]:
 # ----------------------------------------------- filter-decision channel
 # The batch executor's gather-vs-concat branch choices. Recorded whether or
 # not tracing is enabled (bounded + cheap; the benchmarks report the
-# counts) — this channel is the replacement for the unbounded, racy
-# ``core.executor.FILTER_DECISIONS`` module global.
+# counts).
 _FILTER_CHANNEL = DecisionChannel(cap=8192)
 
 # lazily bound to avoid importing metrics before it is needed

@@ -17,6 +17,7 @@ try:
 except ImportError:  # optional dependency — see pyproject.toml [test]
     HAVE_HYPOTHESIS = False
 
+from repro import obs
 from repro.core import engine
 from repro.core.executor import CompiledPushPlan, compile_push_plan
 from repro.core.plan import PushPlan, estimate_cost, execute_push_plan
@@ -165,7 +166,7 @@ def test_filter_decision_log():
     engine.execute_requests(reqs, filter_gather_threshold=0.0)
     counts = X.filter_decision_counts()
     assert counts["concat"] >= 1 and counts["gather"] == 0
-    d = X.FILTER_DECISIONS[0]
+    d = obs.filter_decision_channel().snapshot()[0]
     assert d["table"] == "lineitem" and 0.0 <= d["est_selectivity"] <= 1.0
     X.reset_filter_decisions()
 

@@ -118,18 +118,61 @@ def test_decision_channel_thread_safety():
     assert ch.counts("k") == {k: per for k in range(n_threads)}
 
 
-def test_filter_decisions_deprecated_alias():
-    """The old ``executor.FILTER_DECISIONS`` module global still reads (one
-    release of compat) but is served from the bounded channel."""
-    from repro.core import executor as X
-    X.reset_filter_decisions()
-    q = Q.build_query("Q6")
-    reqs = engine.plan_requests(q, CAT)
-    engine.execute_requests(reqs)
-    log = X.FILTER_DECISIONS               # module __getattr__ alias
-    assert len(log) > 0 and log[0]["table"] == "lineitem"
-    counts = X.filter_decision_counts()
-    assert counts["gather"] + counts["concat"] == len(log)
+def _profiled(tmp_path, body):
+    """Run ``body`` under the JAX profiler; the names of the host events
+    the trace holds under the program-span prefix."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    names = []
+    for path in tmp_path.glob("**/*.xplane.pb"):
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                names += [e.name for e in line.events
+                          if e.name.startswith(obs.PROFILER_PREFIX)]
+    return names
+
+
+def test_same_thread_spans_appear_in_the_profiler_trace(tmp_path):
+    """Every same-thread span of an enabled tracer sits in the profiler
+    trace under the prefix, one event per span, from every thread;
+    detached and instant spans stay host-clock only."""
+    def body():
+        with tracing() as tr:
+            def work(k):
+                with tr.span(f"outer{k}"):
+                    with tr.span("inner"):
+                        pass
+            threads = [threading.Thread(target=work, args=(k,))
+                       for k in range(2)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            det = tr.start("detached")
+            tr.event("instant")
+            tr.end(det)
+
+    names = _profiled(tmp_path, body)
+    p = obs.PROFILER_PREFIX
+    assert sorted(names) == sorted([p + "inner", p + "inner",
+                                    p + "outer0", p + "outer1"])
+
+
+def test_disabled_tracer_emits_nothing_to_the_profiler(tmp_path):
+    def body():
+        tr = get_tracer()
+        assert tr is NULL_TRACER
+        for _ in range(3):
+            with tr.span("residual_prep"):
+                pass
+
+    assert _profiled(tmp_path, body) == []
 
 
 # --------------------------------------------------------------- metrics

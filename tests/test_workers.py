@@ -393,10 +393,23 @@ def test_catalog_mutation_triggers_reship():
         p.close()
 
 
+def _queue_and_work(tr):
+    """Each adopted ``worker_queue`` span paired with the handling span of
+    the same request: same dispatching span, starting where the wait
+    ends."""
+    spans = tr.snapshot()
+    work = [s for s in spans if s.name in ("worker_execute", "worker_fetch")]
+    return [(q, w) for q in spans if q.name == "worker_queue"
+            for w in work if w.parent == q.parent
+            and abs(w.t0 - (q.t0 + q.dur)) < 1e-9]
+
+
 def test_worker_spans_stitched_into_compute_trace(pool):
     """Span-id handoff: worker-side spans come back in the response and
     are adopted under the dispatching compute-side span, echoing it as
-    ``remote_parent`` and carrying the worker's pid."""
+    ``remote_parent`` and carrying the worker's pid. Each request's
+    ``worker_queue`` ends where its ``worker_execute``/``worker_fetch``
+    starts, and both lie inside the dispatching span."""
     q = Q.build_query("Q6")
     reqs = engine.plan_requests(q, CAT)
     dec = {r.req_id: (PUSHDOWN if i % 2 == 0 else PUSHBACK)
@@ -405,9 +418,11 @@ def test_worker_spans_stitched_into_compute_trace(pool):
         runtime.execute_split(reqs, dec, retry=FAST, tier=pool)
     execs = tr.find("worker_execute")
     fetches = tr.find("worker_fetch")
+    queues = tr.find("worker_queue")
     assert execs and fetches
+    assert len(queues) == len(execs) + len(fetches)
     sids = {s.sid: s for s in tr.snapshot()}
-    for sp in execs + fetches:
+    for sp in execs + fetches + queues:
         assert sp.cat == "worker"
         assert sp.attrs["pid"] != os.getpid()     # really remote
         assert sp.dur is not None and sp.dur >= 0
@@ -415,5 +430,34 @@ def test_worker_spans_stitched_into_compute_trace(pool):
         assert sp.attrs["remote_parent"] == sp.parent
         parent = sids[sp.parent]
         assert parent.name in ("storage_execute", "compute_replay")
+        assert parent.t0 <= sp.t0
+        assert sp.t0 + sp.dur <= parent.t0 + parent.dur
+    pairs = _queue_and_work(tr)
+    assert len(pairs) == len(queues)
+    for wq, work in pairs:
+        assert work.attrs["pid"] == wq.attrs["pid"]
     nodes = {sp.attrs["node"] for sp in execs}
     assert nodes <= {0, 1}
+
+
+def test_worker_spans_sit_at_the_workers_own_times(pool):
+    """A request that waits behind busy slots shows the wait as
+    ``worker_queue``; its ``worker_execute`` starts when a slot picked it
+    up, not when the parent sent it."""
+    q = Q.build_query("Q6")
+    reqs = [r for r in engine.plan_requests(q, CAT)
+            if r.part.node_id == 0]
+    dec = {r.req_id: PUSHDOWN for r in reqs}
+    burn_s = 0.4
+    with T.tracing() as tr:
+        t_sent = time.perf_counter() - tr.t0
+        pool.burn(0, burn_s, tasks=2)      # both slots of node 0
+        runtime.execute_split(reqs, dec, retry=FAST, tier=pool)
+    pairs = _queue_and_work(tr)
+    assert pairs
+    first = min(pairs, key=lambda p: p[0].t0)
+    wq, work = first
+    assert work.name == "worker_execute"
+    assert wq.t0 >= t_sent
+    assert wq.dur >= 0.5 * burn_s
+    assert work.t0 >= t_sent + 0.5 * burn_s
