@@ -23,10 +23,14 @@ Map        derive lambdas written against numpy trace through a
            routed to jax.numpy), so ``np.maximum``/``np.isin``-style
            derives stay inside the jit instead of host round-trips
 Aggregate  keyed: mixed-radix key codes over the *observed* per-key value
-           bounds (see below) -> ``jax.ops.segment_sum``-family
-           reductions, group compaction by cumsum+searchsorted — no sort
-           anywhere; when the code domain is too large, integral keys
-           sort as one packed code and non-integral keys lexsort.
+           bounds (see below). A domain of up to ``_AGG_DENSE_CAP``
+           codes reduces as one dense masked reduction over the rows,
+           with no scatter: XLA:TPU serializes a scatter-add's updates
+           that collide on one address. Larger domains reduce by
+           ``jax.ops.segment_sum``-family scatters. Group compaction is
+           a cumsum+searchsorted, with no sort; beyond ``_AGG_DOM_CAP``
+           codes, integral keys sort as one packed code and non-integral
+           keys lexsort.
            keyless: masked whole-column reductions
 Join       build-host / probe-device: every right side is materialized
            host-side as a named build leaf, and a dense key LUT over its
@@ -90,7 +94,7 @@ import os
 import re
 import threading
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -104,6 +108,7 @@ from repro.queryproc.table import ColumnTable
 _MIN_BUCKET = 16
 _LUT_CAP = 1 << 25       # max dense key-LUT domain (256 MiB of int64)
 _AGG_DOM_CAP = 1 << 18   # max mixed-radix aggregate code domain
+_AGG_DENSE_CAP = 4096    # max code domain reduced densely (sweep: PERF.md)
 _LEX_CODE_CAP = 1 << 62  # max key domain sorted as one packed int64 code
 _RESPEC_CAP = 8          # re-specializations before settling on the oracle
 
@@ -229,7 +234,8 @@ class _Stage:
     ``pyop`` (if any) then runs host-side on the materialized root tables
     and its output enters the environment as ``out_name``. ``names`` /
     ``luts`` (the stage's jit inputs) are filled post-observation by
-    ``_build_jits``."""
+    ``_build_jits``; ``agg_ways`` (how many keyed aggregates its program
+    lowers ``dense``, ``scatter`` or ``sort``) by the program's trace."""
     index: int
     roots: Tuple[ir.Node, ...]
     jit_roots: Tuple[ir.Node, ...]
@@ -238,6 +244,7 @@ class _Stage:
     names: List[str] = dataclasses.field(default_factory=list)
     luts: List[Tuple[str, str, str, bool]] = dataclasses.field(
         default_factory=list)
+    agg_ways: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -529,11 +536,12 @@ def _make_stage_fn(stage: _Stage, art: _Artifact) -> Callable:
     def stage_fn(inputs):
         import jax.numpy as jnp
         ctx: Dict = {"memo": {}, "flags": [], "respec": [],
-                     "inputs": inputs, "art": art}
+                     "inputs": inputs, "art": art, "agg_ways": []}
         outs = []
         for root in stage.jit_roots:
             mt = _lower(root, ctx)
             outs.append({"cols": dict(mt.cols), "valid": mt.valid})
+        stage.agg_ways = dict(Counter(ctx["agg_ways"]))
         flag = jnp.asarray(False)
         for f in ctx["flags"]:
             flag = flag | f
@@ -616,6 +624,7 @@ def _lower_aggregate(node: ir.Aggregate, t: _MT, ctx: Dict) -> _MT:
     spec = ctx["art"].obs["agg"][id(node)]
     if spec[0] == "code":
         return _agg_code(node, t, spec, ctx)
+    ctx["agg_ways"].append("sort")
     return _agg_lex(node, t, spec, ctx)
 
 
@@ -672,12 +681,18 @@ def _radix_code(node: ir.Aggregate, t: _MT, mins, dims, ctx: Dict):
 
 def _agg_code(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
     """Sort-free grouped aggregation: each row's keys encode into one
-    mixed-radix code over the observed per-key bounds, segment reductions
-    run directly on the codes (ascending code order == the ascending
-    lexicographic key order np.unique gives the interpreter), and group
-    compaction is a cumsum + searchsorted over the code domain. Rows
-    whose keys left the observed domain raise the in-trace respec flag;
-    invalid rows park in the extra segment ``D``."""
+    mixed-radix code over the observed per-key bounds, the group
+    reductions run directly on the codes (ascending code order == the
+    ascending lexicographic key order np.unique gives the interpreter),
+    and group compaction is a cumsum + searchsorted over the code domain.
+    Rows whose keys left the observed domain raise the in-trace respec
+    flag.
+
+    A domain of at most ``_AGG_DENSE_CAP`` codes reduces densely
+    (``_dense_group_reduce``): a scatter-add serializes updates that
+    collide on one address on XLA:TPU, and in a small domain nearly all
+    of them do. Larger domains scatter (``segment_sum`` family), with
+    invalid rows parked in the extra segment ``D``."""
     import jax
     import jax.numpy as jnp
 
@@ -685,51 +700,85 @@ def _agg_code(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
     D, strides, code = _radix_code(node, t, mins, dims, ctx)
     key_dtypes = [t.cols[k].dtype for k in node.keys]
 
-    # Small domains lower to a one-hot contraction (XLA:CPU dots are
-    # multi-threaded; its segment scatters are not). Large domains keep
-    # the scatter — the N x D one-hot would not fit the cache anyway.
-    n_rows = t.valid.shape[0]
-    onehot = None
-    if D <= 512 and n_rows * D <= (1 << 22):
-        onehot = (code[:, None] == jnp.arange(D)[None, :]) & t.valid[:, None]
-        onehot_f = onehot.astype(jnp.float64)
-        cnt = jnp.sum(onehot, axis=0).astype(jnp.int64)
+    if D <= _AGG_DENSE_CAP:
+        ctx["agg_ways"].append("dense")
+        wanted = {("sum" if fn == "mean" else fn, col): t.cols[col]
+                  for _, fn, col in node.aggs if fn != "count"}
+        cnt, present, dense = _dense_group_reduce(code, t.valid, D, wanted)
+
+        def group(kind, col):
+            return dense[(kind, col)]
     else:
+        ctx["agg_ways"].append("scatter")
         gid = jnp.where(t.valid, code, D)
         cnt = jax.ops.segment_sum(t.valid.astype(jnp.int64), gid,
                                   num_segments=D + 1)[:D]
-    present = cnt > 0
+        present = cnt > 0
+
+        def group(kind, col):
+            vals = t.cols[col]
+            if kind == "sum":
+                masked = jnp.where(t.valid, vals, 0).astype(jnp.float64)
+                return jax.ops.segment_sum(masked, gid,
+                                           num_segments=D + 1)[:D]
+            sent = _minmax_sentinel(vals.dtype, want_max=(kind == "min"))
+            red = (jax.ops.segment_min if kind == "min"
+                   else jax.ops.segment_max)
+            return red(jnp.where(t.valid, vals, sent), gid,
+                       num_segments=D + 1)[:D]
     n_groups = jnp.sum(present)
     ranks = jnp.cumsum(present.astype(jnp.int64))
     oc = jnp.clip(jnp.searchsorted(ranks, jnp.arange(1, D + 1)), 0, D - 1)
     out = {}
     for k, mn, d, stp, dt in zip(node.keys, mins, dims, strides, key_dtypes):
         out[k] = (mn + (oc // stp) % d).astype(dt)
-    def gsum(vals):
-        masked = jnp.where(t.valid, vals, 0).astype(jnp.float64)
-        if onehot is not None:
-            return masked @ onehot_f
-        return jax.ops.segment_sum(masked, gid, num_segments=D + 1)[:D]
-
-    def gminmax(vals, fn):
-        sent = _minmax_sentinel(vals.dtype, want_max=(fn == "min"))
-        if onehot is not None:
-            red = jnp.min if fn == "min" else jnp.max
-            return red(jnp.where(onehot, vals[:, None], sent), axis=0)
-        red = jax.ops.segment_min if fn == "min" else jax.ops.segment_max
-        return red(jnp.where(t.valid, vals, sent), gid,
-                   num_segments=D + 1)[:D]
-
     for name, fn, col in node.aggs:
         if fn == "count":
             out[name] = cnt[oc]
-        elif fn == "sum":
-            out[name] = gsum(t.cols[col])[oc]
         elif fn == "mean":
-            out[name] = (gsum(t.cols[col]) / jnp.maximum(cnt, 1))[oc]
+            out[name] = (group("sum", col) / jnp.maximum(cnt, 1))[oc]
         else:
-            out[name] = gminmax(t.cols[col], fn)[oc]
+            out[name] = group(fn, col)[oc]
     return _MT(out, jnp.arange(D) < n_groups)
+
+
+def _dense_group_reduce(code, valid, D: int, wanted: Dict):
+    """Per code: its valid rows' count, whether it has any, and per
+    ``(kind, column)`` of ``wanted`` (kind ``sum`` in float64, ``min`` or
+    ``max``) the reduction of that column over them. One variadic reduce
+    over the (D, N) mask ``valid & (code == d)`` computes all of them; the
+    mask is broadcast, never stored, because XLA fuses it into the reduce,
+    on XLA:TPU and on XLA:CPU alike. (A lone count would not do: XLA:CPU
+    rewrites a single-operand reduce into a tree of partial reductions
+    over a stored N x D input, hence the ``any`` beside it.) The count
+    sums int32 (exact: N < 2^31) and widens to int64."""
+    import jax
+    import jax.numpy as jnp
+
+    hit = ((code.astype(jnp.int32)[None, :]
+            == jnp.arange(D, dtype=jnp.int32)[:, None]) & valid[None, :])
+    kinds = ["sum", "any"]
+    operands = [hit.astype(jnp.int32), hit]
+    inits = [np.int32(0), np.bool_(False)]
+    for (kind, _), vals in wanted.items():
+        if kind == "sum":
+            vals = vals.astype(jnp.float64)
+            ident = np.float64(0)
+        else:
+            ident = np.asarray(
+                _minmax_sentinel(vals.dtype, want_max=(kind == "min")),
+                vals.dtype)
+        kinds.append(kind)
+        operands.append(jnp.where(hit, vals[None, :], ident))
+        inits.append(ident)
+    combine = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum,
+               "any": jnp.logical_or}
+    reduced = jax.lax.reduce(
+        tuple(operands), tuple(inits),
+        lambda a, b: tuple(combine[k](x, y) for k, x, y in zip(kinds, a, b)),
+        (1,))
+    return (reduced[0].astype(jnp.int64), reduced[1],
+            dict(zip(wanted, reduced[2:])))
 
 
 def _agg_lex(node: ir.Aggregate, t: _MT, spec: Tuple, ctx: Dict) -> _MT:
@@ -1056,7 +1105,8 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
     ``residual_device`` (the jit call until its guard flags are on the
     host: device work plus any wait behind other callers) and
     ``residual_d2h`` (readback). ``residual.h2d_bytes`` counts the bytes
-    put on the device."""
+    put on the device; ``residual.agg.dense``, ``.scatter`` and ``.sort``
+    count the call's keyed aggregates by their lowering."""
     import jax
     from repro.compiler import interpreter
 
@@ -1126,6 +1176,8 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
                         out = art.jit_fns[st.index](dev_inputs)
                         respec = bool(out["respec"])
                         fallback = bool(out["fallback"])
+                    for way, n in st.agg_ways.items():
+                        m.counter(f"residual.agg.{way}").inc(n)
                     if respec:
                         raise TensorFallback(
                             "aggregate keys left the observed domain",

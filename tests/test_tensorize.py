@@ -11,6 +11,7 @@ joins fall back gracefully, and ``compile_expr_jnp`` matches
 ``compile_expr`` bitwise on random columns.
 """
 import os
+import types
 
 import numpy as np
 import pytest
@@ -186,6 +187,38 @@ def test_residual_steps_cover_the_residual_span(qid, cat4):
                 assert s.t0 >= kids[k - 1].t0 + kids[k - 1].dur
         shares.append(sum(s.dur for s in kids) / rc.dur)
     assert max(shares) >= 0.95, shares
+
+
+AGG_WAYS = ("dense", "scatter", "sort")
+
+
+@pytest.mark.parametrize("qid,way", [("Q5", "dense"), ("Q10", "scatter"),
+                                     ("Q18", "sort")])
+def test_agg_way_counters_count_each_executed_aggregate(
+        qid, way, cat4, metrics, monkeypatch):
+    """``residual.agg.<way>`` grows by one per keyed aggregate a jitted
+    stage call runs that way, on a call that compiles and on one that
+    hits the jit cache alike; the observe run calls no stage. Q5 groups
+    by nation (at most 25 codes). Q10 groups by customer: 150,000 codes
+    at SF1 scatter, and Q18 by order key: 6M codes at SF1 sort. At this
+    scale (4,000 customers, 60,000 orders) the test lowers the cap that
+    sends each there."""
+    if way == "scatter":
+        monkeypatch.setattr(tensorize, "_AGG_DENSE_CAP", 1 << 10)
+    if way == "sort":
+        monkeypatch.setattr(tensorize, "_AGG_DOM_CAP", 1 << 12)
+    q = compile_query(qid)
+    cfg = engine.EngineConfig(mode="eager", residual="tensor")
+
+    def counts():
+        return {w: metrics.counter(f"residual.agg.{w}").value
+                for w in AGG_WAYS}
+
+    engine.run_query(q, cat4, cfg)                   # observe
+    assert counts() == dict.fromkeys(AGG_WAYS, 0)
+    runs = [engine.run_query(q, cat4, cfg).residual_jit for _ in range(2)]
+    assert [(r["misses"], r["hits"]) for r in runs] == [(1, 0), (0, 1)]
+    assert counts() == {w: 2 * (w == way) for w in AGG_WAYS}
 
 
 def test_stage_error_raises_instead_of_falling_back(monkeypatch, metrics):
@@ -422,6 +455,69 @@ def test_huge_domain_aggregate_sorts(dtype, spec_len):
         assert engine.results_equal(interpreter.run(res, merged), run.table)
     assert not run.fell_back
     assert art.gen == (1 if spec_len == 3 else 0)
+
+
+def _jit_keyed_agg(node, spec):
+    """One keyed aggregate's lowering, jitted on explicit padded columns
+    and a validity mask. The jitted function returns the output columns,
+    their validity mask and the in-trace respec flag; ``ways`` collects
+    the lowering the trace chose."""
+    import jax
+    import jax.numpy as jnp
+    art = types.SimpleNamespace(obs={"agg": {id(node): spec}})
+    ways = []
+
+    def fn(cols, valid):
+        ctx = {"art": art, "respec": [], "agg_ways": ways}
+        mt = tensorize._lower_aggregate(node, tensorize._MT(cols, valid),
+                                        ctx)
+        return mt.cols, mt.valid, jnp.any(jnp.stack(ctx["respec"]))
+
+    return jax.jit(fn), ways
+
+
+_CAP = tensorize._AGG_DENSE_CAP
+
+
+@pytest.mark.parametrize("fn", ["count", "sum", "mean", "min", "max"])
+@pytest.mark.parametrize("n", [16, 1 << 20])
+@pytest.mark.parametrize("D", [1, 14, _CAP, _CAP + 1])
+def test_code_aggregate_matches_interpreter(D, n, fn):
+    """A keyed aggregate over a code domain of D keys reduces densely up
+    to the cap, with no scatter in its program, and scatters above it.
+    Either way, over an int and a float column with empty groups (odd
+    codes never occur), it returns the interpreter's table; with every
+    row invalid it returns no group; a valid key outside the observed
+    domain raises the respec flag, and an invalid one does not."""
+    import jax
+    rng = np.random.default_rng(D * 31 + n)
+    node = ir.Aggregate(ir.Merged("t"), ("k",),
+                        (("i", fn, "iv"), ("f", fn, "fv")))
+    f, ways = _jit_keyed_agg(node, ("code", (5,), (D,)))
+    cols = {"k": 5 + 2 * rng.integers(0, (D + 1) // 2, n),
+            "iv": rng.integers(-1000, 1000, n),
+            "fv": rng.normal(size=n)}
+    valid = rng.random(n) < 0.7
+    valid[:2] = True
+    with jax.enable_x64(True):
+        text = f.lower(cols, valid).as_text()
+        out, ovalid, respec = f(cols, valid)
+        got = tensorize._unpad({"cols": out, "valid": ovalid})
+        ref = interpreter.run(node, {"t": ColumnTable(cols).filter(valid)})
+        assert engine.results_equal(ref, got) and not respec
+        assert len(got) == len(np.unique(cols["k"][valid]))
+
+        _, none_valid, respec = f(cols, np.zeros(n, bool))
+        assert not np.asarray(none_valid).any() and not respec
+
+        oob = dict(cols, k=cols["k"].copy())
+        oob["k"][:2] = (4, 5)                        # below the domain
+        assert bool(f(oob, valid)[2])
+        oob["k"][:2] = (5, 5 + D)                    # above it
+        assert bool(f(oob, valid)[2])
+        assert not bool(f(oob, valid & (np.arange(n) != 1))[2])
+    assert ways == ["dense" if D <= _CAP else "scatter"]
+    assert ("scatter" in text) == (D > _CAP)
 
 
 def test_shape_buckets_share_jitted_programs():
