@@ -1104,9 +1104,16 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
     ``jax.device_put`` of the inputs; traced, it waits for the copy),
     ``residual_device`` (the jit call until its guard flags are on the
     host: device work plus any wait behind other callers) and
-    ``residual_d2h`` (readback). ``residual.h2d_bytes`` counts the bytes
-    put on the device; ``residual.agg.dense``, ``.scatter`` and ``.sort``
-    count the call's keyed aggregates by their lowering."""
+    ``residual_d2h`` (readback). A ``PyOp`` stage runs its host function,
+    with the host tables it reads, in a fifth step, ``residual_pyop``
+    (attrs ``qid``, ``stage``). A guard trip's oracle replay, and the
+    re-observation and rebuild of ``_respecialize``, run in one
+    ``residual_fallback`` span (attrs ``qid``, ``reason``, ``respec``);
+    so does every later call's replay once the respecialization cap has
+    disabled the artifact (``reason`` "disabled").
+    ``residual.h2d_bytes`` counts the bytes put on the device;
+    ``residual.agg.dense``, ``.scatter`` and ``.sort`` count the call's
+    keyed aggregates by their lowering."""
     import jax
     from repro.compiler import interpreter
 
@@ -1115,8 +1122,11 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
     m = get_metrics()
     if art.disabled:
         m.counter("residual.fallbacks").inc()
-        return TensorRun(table=interpreter.run(residual, merged),
-                         fell_back=True, n_stages=len(art.stages))
+        with tr.span("residual_fallback", "compiler", qid=qid,
+                     reason="disabled", respec=False):
+            table = interpreter.run(residual, merged)
+        return TensorRun(table=table, fell_back=True,
+                         n_stages=len(art.stages))
     if art.obs is None:
         with art.lock:
             if art.obs is None:
@@ -1192,10 +1202,12 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
                             out_tabs[id(root)] = _unpad(o)
                         del out, dev_inputs      # frees the device buffers
                 if st.pyop is not None:
-                    tables = [out_tabs[id(r)] if id(r) in out_tabs
-                              else host_tab(art.leaf_names[id(r)])
-                              for r in st.roots]
-                    t = st.pyop.fn(*tables)
+                    with tr.span("residual_pyop", "compiler", qid=qid,
+                                 stage=st.index):
+                        tables = [out_tabs[id(r)] if id(r) in out_tabs
+                                  else host_tab(art.leaf_names[id(r)])
+                                  for r in st.roots]
+                        t = st.pyop.fn(*tables)
                     env[st.out_name] = t
                     imemo[id(st.pyop)] = t
                 else:
@@ -1205,10 +1217,12 @@ def execute(residual: ir.Node, merged: Dict[str, ColumnTable],
     except TensorFallback as e:
         fell_back = True
         m.counter("residual.fallbacks").inc()
-        if e.respec:
-            result = _respecialize(art, residual, merged)
-        if result is None:
-            result = interpreter.run(residual, merged)
+        with tr.span("residual_fallback", "compiler", qid=qid,
+                     reason=str(e), respec=e.respec):
+            if e.respec:
+                result = _respecialize(art, residual, merged)
+            if result is None:
+                result = interpreter.run(residual, merged)
     except Exception:
         m.counter("residual.errors").inc()
         raise

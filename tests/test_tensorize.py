@@ -10,7 +10,9 @@ respecialize (gen bump) without changing results, duplicate-right-key
 joins fall back gracefully, and ``compile_expr_jnp`` matches
 ``compile_expr`` bitwise on random columns.
 """
+import contextlib
 import os
+import time
 import types
 
 import numpy as np
@@ -159,32 +161,62 @@ def cat4():
     return tpch.build_catalog(sf=4, num_nodes=2, rows_per_partition=16_000)
 
 
-@pytest.mark.parametrize("qid", ["Q3", "Q5", "Q10", "Q18"])
-def test_residual_steps_cover_the_residual_span(qid, cat4):
+def _steps(art, qid):
+    """The child spans, with their attrs, that one warm call of ``art``
+    opens inside ``residual_compute``: the four steps of each jitted
+    stage, then its PyOp's host step where the stage has one."""
+    want = []
+    for st in art.stages:
+        if st.jit_roots:
+            want += [(n, {"qid": qid, "stage": st.index, "hit": True})
+                     for n in RESIDUAL_STEPS]
+        if st.pyop is not None:
+            want.append(("residual_pyop", {"qid": qid, "stage": st.index}))
+    return want
+
+
+@pytest.fixture(scope="module")
+def cat32():
+    """Larger still, for the PyOp queries: at sf=4 Q15's and Q22's
+    residuals last 1 to 3 ms on the CPU, and the fixed glue of a traced
+    call (dispatch, the x64 context, span bookkeeping, about 0.1 ms) is
+    4 to 8 % of that."""
+    return tpch.build_catalog(sf=32, num_nodes=2, rows_per_partition=16_000)
+
+
+PYOP_QIDS = ("Q15", "Q22")
+
+
+@pytest.mark.parametrize("qid", ["Q3", "Q5", "Q10", "Q18", *PYOP_QIDS])
+def test_residual_steps_cover_the_residual_span(qid, request):
     """Traced, each jitted stage call opens prep, h2d, device and d2h in
-    that order, inside ``residual_compute``, and the four cover at least
-    95 % of it. Of three traced runs the best covered counts, so that a
-    preemption of this process between two steps reads as noise."""
+    that order, and a PyOp stage (Q15's, Q22's) its host step
+    ``residual_pyop`` after them, all inside ``residual_compute``; the
+    steps cover at least 95 % of it. Of three traced runs the best
+    covered counts, so that a preemption of this process between two
+    steps reads as noise."""
     from repro.obs.trace import tracing
+    cat = request.getfixturevalue("cat32" if qid in PYOP_QIDS else "cat4")
     q = compile_query(qid)
     cfg = engine.EngineConfig(mode="eager", residual="tensor")
-    engine.run_query(q, cat4, cfg)                   # observe
-    engine.run_query(q, cat4, cfg)                   # compile
-    n_stages = sum(f is not None
-                   for f in tensorize._artifact(q.residual).jit_fns)
+    engine.run_query(q, cat, cfg)                    # observe
+    engine.run_query(q, cat, cfg)                    # compile
+    want = _steps(tensorize._artifact(q.residual), qid)
+    assert [n for n, _a in want].count("residual_pyop") == (
+        qid in PYOP_QIDS)
     shares = []
     for _ in range(3):
         with tracing() as tr:
-            engine.run_query(q, cat4, cfg)
+            engine.run_query(q, cat, cfg)
         (rc,) = tr.find("residual_compute")
         kids = sorted((s for s in tr.snapshot() if s.parent == rc.sid),
                       key=lambda s: s.t0)
-        assert [s.name for s in kids] == list(RESIDUAL_STEPS) * n_stages
+        assert [(s.name, s.attrs) for s in kids] == want
         for k, s in enumerate(kids):
             assert rc.t0 <= s.t0 and s.t0 + s.dur <= rc.t0 + rc.dur
-            assert s.attrs == {"qid": qid, "stage": k // 4, "hit": True}
             if k:
                 assert s.t0 >= kids[k - 1].t0 + kids[k - 1].dur
+        assert not tr.find("residual_fallback")
         shares.append(sum(s.dur for s in kids) / rc.dur)
     assert max(shares) >= 0.95, shares
 
@@ -409,10 +441,36 @@ def _tab(keys, vals=None):
     return ColumnTable({"k": keys, "v": vals})
 
 
-def test_respecialize_on_domain_growth():
+@contextlib.contextmanager
+def _replay_in_one_fallback_span(monkeypatch, owner, name, qid, reason,
+                                 respec):
+    """Trace the block, which trips one lowering guard, and check that
+    ``owner.name`` (the host replay) ran once, inside the one
+    ``residual_fallback`` span, whose attrs give the guard's reason."""
+    from repro.obs.trace import tracing
+    fn, calls = getattr(owner, name), []
+
+    def timed(*a, **kw):
+        t0 = time.perf_counter()
+        try:
+            return fn(*a, **kw)
+        finally:
+            calls.append((t0, time.perf_counter()))
+
+    monkeypatch.setattr(owner, name, timed)
+    with tracing() as tr:
+        yield
+    (fb,) = tr.find("residual_fallback")
+    assert fb.attrs == {"qid": qid, "reason": reason, "respec": respec}
+    ((t0, t1),) = calls
+    assert tr.t0 + fb.t0 <= t0 and t1 <= tr.t0 + fb.t0 + fb.dur
+
+
+def test_respecialize_on_domain_growth(monkeypatch):
     """Keys outside the observed domain trip the in-trace guard: that run
-    falls back (still correct), the artifact respecializes (gen bump),
-    and the next run jits cleanly over the widened bounds."""
+    falls back (still correct) and the artifact respecializes (gen bump)
+    inside one ``residual_fallback`` span; the next run jits cleanly over
+    the widened bounds."""
     res = _agg_residual()
     small = {"t": _tab(np.arange(64) % 4)}
     big = {"t": _tab(np.arange(64) % 4 + 100)}       # disjoint key range
@@ -421,13 +479,40 @@ def test_respecialize_on_domain_growth():
     assert art.gen == 0
     ok = tensorize.execute(res, small)
     assert not ok.fell_back
-    r_fb = tensorize.execute(res, big)               # oob -> guard trips
+    with _replay_in_one_fallback_span(
+            monkeypatch, tensorize, "_respecialize", "Qa",
+            "aggregate keys left the observed domain", respec=True):
+        r_fb = tensorize.execute(res, big, "Qa")     # oob -> guard trips
     assert r_fb.fell_back
     assert engine.results_equal(interpreter.run(res, big), r_fb.table)
     assert art.gen == 1 and art.respecs == 1
     r_ok = tensorize.execute(res, big)               # widened spec jits
     assert not r_ok.fell_back and not art.disabled
     assert engine.results_equal(interpreter.run(res, big), r_ok.table)
+
+
+def test_disabled_artifact_replays_in_a_fallback_span(monkeypatch, metrics):
+    """Past the respecialization cap the artifact settles on the oracle:
+    every later call counts one ``residual.fallbacks`` and replays inside
+    its own ``residual_fallback`` span, reason "disabled"."""
+    monkeypatch.setattr(tensorize, "_RESPEC_CAP", 1)
+    res = _agg_residual()
+    tabs = [{"t": _tab(np.arange(64) % 4 + 100 * k)} for k in range(3)]
+    tensorize.execute(res, tabs[0])                  # observe
+    art = tensorize._artifact(res)
+    assert tensorize.execute(res, tabs[1]).fell_back     # respec 1
+    assert not art.disabled
+    assert tensorize.execute(res, tabs[2]).fell_back     # past the cap
+    assert art.disabled
+    for t in tabs:
+        before = metrics.counter("residual.fallbacks").value
+        with _replay_in_one_fallback_span(
+                monkeypatch, interpreter, "run", "Qd", "disabled",
+                respec=False):
+            run = tensorize.execute(res, t, "Qd")
+        assert run.fell_back
+        assert metrics.counter("residual.fallbacks").value == before + 1
+        assert engine.results_equal(interpreter.run(res, t), run.table)
 
 
 @pytest.mark.parametrize("dtype,spec_len", [(np.int64, 3), (np.float64, 1)])
@@ -540,18 +625,24 @@ def test_shape_buckets_share_jitted_programs():
         assert got.jit_hits == 1
 
 
-def test_join_duplicate_right_keys_falls_back():
+def test_join_duplicate_right_keys_falls_back(monkeypatch):
     """The dense-LUT probe requires unique build keys; a many-to-many
-    right side must fall back to the interpreter with the same table."""
+    right side must fall back to the interpreter with the same table.
+    Traced, the interpreter's replay runs inside one
+    ``residual_fallback`` span that gives the guard's reason."""
     res = ir.Join(ir.Merged("l"), ir.Merged("r"), "k", "rk")
     merged = {"l": ColumnTable({"k": np.asarray([1, 2, 3]),
                                 "x": np.asarray([1.0, 2.0, 3.0])}),
               "r": ColumnTable({"rk": np.asarray([2, 2, 3]),
                                 "y": np.asarray([10.0, 20.0, 30.0])})}
+    ref = interpreter.run(res, merged)
     tensorize.execute(res, merged)                   # observe
-    run = tensorize.execute(res, merged)
+    with _replay_in_one_fallback_span(
+            monkeypatch, interpreter, "run", "Qj",
+            "duplicate right join keys (m:n)", respec=False):
+        run = tensorize.execute(res, merged, "Qj")
     assert run.fell_back
-    assert engine.results_equal(interpreter.run(res, merged), run.table)
+    assert engine.results_equal(ref, run.table)
 
 
 def test_join_non_integer_keys_use_sorted_probe():
